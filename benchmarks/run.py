@@ -28,6 +28,8 @@ def main() -> None:
                     help="table execution path; 'scan' fuses each cell's "
                          "seeds into one repro.grid dispatch")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     seeds = tuple(int(s) for s in args.seeds.split(","))
     only = args.only.split(",") if args.only else BENCHES
 

@@ -126,7 +126,8 @@ def results_from_scan(cfg, s, out, *, wall_time_s: float, seed: int,
     # charge uploads at the ACTUAL granted-cohort size per round (dropout
     # strategies can grant fewer than m active clients), matching the
     # loop engine's per-selected-client accounting (replicated.py)
-    codec_bytes = codec_nbytes(cfg.upload_codec, s.params)
+    # shapes only, read from the output: the solo scan donated s.params
+    codec_bytes = codec_nbytes(cfg.upload_codec, out.params)
     upload_bytes = codec_bytes * int(np.asarray(out.granted).sum())
     download_bytes = s.model_bytes * cfg.m * cfg.rounds
 
@@ -321,6 +322,8 @@ def run_federated_scan(cfg, s, t_start: float, *, telemetry=None,
     with ctimer, trace_capture(telemetry, label="run_scan") as capturing:
         run = jitted_run_scan(s.model, cfg.client, spec)
         with live_sink(telemetry if live else None), stage("scan"):
+            # s.params is donated on TPU/GPU: nothing below may read it;
+            # out.params has the same avals where shapes are needed
             out = run(s.params, *operands)
             if live or capturing is not None:
                 # drain the in-scan debug callbacks before the sink
@@ -338,10 +341,10 @@ def run_federated_scan(cfg, s, t_start: float, *, telemetry=None,
         from repro.telemetry.metrics import emit_scan_rounds, run_end_payload
         from repro.telemetry.profile import cached_cost_card
         telemetry.emit("compile", seconds=ctimer.seconds, program="run_scan",
-                       cost_card=cached_cost_card(run, s.params, *operands))
+                       cost_card=cached_cost_card(run, out.params, *operands))
         emit_scan_rounds(
             telemetry, out, uses_shapley=spec_sel.uses_shapley,
-            codec_bytes=codec_nbytes(cfg.upload_codec, s.params),
+            codec_bytes=codec_nbytes(cfg.upload_codec, out.params),
             model_bytes=s.model_bytes,
             emask=eval_mask(cfg.rounds, cfg.eval_every))
         telemetry.emit("run_end", **run_end_payload(

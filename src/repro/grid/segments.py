@@ -245,13 +245,17 @@ def run_segments(model, ccfg, spec: ScanSpec, batch: ReplicaBatch, *,
             telemetry.emit("segment_start", segment=seg,
                            t0=seg * k_rounds, rounds=k_rounds, tag=tag,
                            replicas=n_replicas)
+        # the step donates the carry on TPU/GPU: a dispatch that fails
+        # after launch has consumed it, so a retry re-dispatches a copy
+        # taken before the launch, never the donated buffers
+        backup = jax.tree.map(jnp.copy, carry) if retries else None
         attempt = 0
         while True:
             try:
                 with ctimer, live_sink(telemetry if live else None), \
                         stage("segment"):
                     out = step(*args)
-                    if telemetry is not None or attempt > 0:
+                    if telemetry is not None or retries:
                         # taps must land (and the segment be timed) before
                         # the next dispatch is enqueued; under retry, force
                         # async dispatch errors to surface HERE
@@ -266,10 +270,12 @@ def run_segments(model, ccfg, spec: ScanSpec, batch: ReplicaBatch, *,
                     telemetry.emit("segment_retry", segment=seg,
                                    attempt=attempt, tag=tag)
                 time.sleep(retry_backoff_s * (2 ** (attempt - 1)))
+                args = (jax.tree.map(jnp.copy, backup),) + args[1:]
         if (compile_stats or telemetry is not None) and seg == start:
             # the step's cost card (one cached AOT probe, §17): flops,
             # bytes, per-device peak memory, roofline terms
-            card = cached_cost_card(step, *args)
+            # out.carry stands in for the donated input carry: same avals
+            card = cached_cost_card(step, out.carry, *args[1:])
             if card is not None:
                 flops = card.get("flops", float("nan"))
                 peak_bytes = card.get("peak_bytes")

@@ -38,7 +38,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.selection_jax import DeviceSelectorState
@@ -104,11 +103,11 @@ def _client_sharded_step_cached(model, ccfg, spec: ScanSpec, mesh):
                               sv=rep, utility_evals=rep, sv_truncated=rep,
                               test_acc=rep, val_loss=rep, granted=rep,
                               quarantined=rep)
-    # check_rep=False: the round outputs ARE replicated over clients (the
+    # check_vma=False: the round outputs ARE replicated over clients (the
     # psum-combined cohort is identical on every shard) but shard_map's
     # replication checker cannot prove it through the scan
-    sm = shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    sm = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return jax.jit(sm)
 
 

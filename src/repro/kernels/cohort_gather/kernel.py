@@ -11,8 +11,11 @@ beyond the M selected rows.
 Grid: (M, D // BLOCK_D).  Program (i, j) copies block j of row ids[i].
 The index_map receives the prefetched ids ref as a trailing argument
 (PrefetchScalarGridSpec contract, same as `prefix_avg`); block indices
-are in block units, and with a block shape of (1, BLOCK_D) the row-block
-index IS the row id.
+are in block units.  The table is viewed (free reshape) as (N, 1, D) and
+tiled by (squeezed, 1, BLOCK_D) blocks, so the leading block index IS the
+row id.  Mosaic requires a block's last two dims to be (8k, 128k) or the
+array's own; a (1, BLOCK_D) block of the 2-D (N, D) table is refused, a
+(1, BLOCK_D) tail of the (N, 1, D) view matches the array's unit dim.
 """
 from __future__ import annotations
 
@@ -49,14 +52,17 @@ def cohort_gather_kernel(table: jax.Array, ids: jax.Array, *,
         num_scalar_prefetch=1,
         grid=(m, d // block_d),
         in_specs=[
-            # data-dependent row fetch: block row index = the cohort id
-            pl.BlockSpec((1, block_d), lambda i, j, ids: (ids[i], j)),
+            # data-dependent row fetch: leading block index = the cohort id
+            pl.BlockSpec((pl.Squeezed(), 1, block_d),
+                         lambda i, j, ids: (ids[i], 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, block_d), lambda i, j, ids: (i, j)),
+        out_specs=pl.BlockSpec((pl.Squeezed(), 1, block_d),
+                               lambda i, j, ids: (i, 0, j)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _gather_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, d), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((m, 1, d), table.dtype),
         interpret=interpret,
-    )(ids.astype(jnp.int32), table)
+    )(ids.astype(jnp.int32), table.reshape(n, 1, d))
+    return out.reshape(m, d)

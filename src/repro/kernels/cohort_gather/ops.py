@@ -2,11 +2,12 @@
 
 Two regimes behind one call:
 
-  * dense (`axis_name=None`) — the whole (N, ...) stack is local.  Big
-    leaves route to the Pallas kernel on TPU (`use_kernel=None` resolves
-    from the backend: the interpreter adds pure overhead to a copy, and
-    `jnp.take` IS the bitwise reference, so off-TPU the ref is used);
-    small leaves always take the ref, mirroring `prefix_avg`.
+  * dense (`axis_name=None`) — the whole (N, ...) stack is local.  On
+    TPU (`use_kernel=None`) leaves of at least one BLOCK_D tile run the
+    natively compiled Pallas kernel; off-TPU the interpreter would add
+    pure overhead to a copy, and `jnp.take` IS the bitwise reference, so
+    the ref is used.  Small leaves always take the ref, mirroring
+    `prefix_avg`.
 
   * client-sharded (`axis_name="clients"`) — `arr` is this shard's
     (N/devices, ...) block inside a `shard_map` body and `ids` is the
@@ -69,7 +70,8 @@ def cohort_take(arr: jax.Array, ids: jax.Array, *,
     client-axis-sharded stack (see `_cross_shard_take`); otherwise the
     dense single-device gather.  `use_kernel=None` resolves to
     TPU-only (a copy gains nothing from the Pallas interpreter);
-    `interpret=None` derives from the backend like the other kernels.
+    `interpret=None` derives from the backend like the other kernels, so
+    on TPU the kernel always runs compiled.
     """
     if axis_name is not None:
         return _cross_shard_take(arr, ids, axis_name)

@@ -5,9 +5,23 @@ round (round_engine.py).  The old path was a per-leaf chain of XLA kernels
 — abs-max pass, quant pass, dequant pass, full-row `lax.top_k` (a sort)
 plus a dense zeros+scatter — each materialising an (M, D) intermediate in
 HBM.  Here the whole roundtrip is ONE pass: each grid step DMAs one row
-(1, D) into VMEM, computes abs-max -> int8 quantise -> dequantise (and the
-exact top-k keep mask for the sparse codecs) entirely on-chip, and writes
-the reconstructed row back.  HBM traffic is the floor: read D, write D.
+into VMEM, computes abs-max -> int8 quantise -> dequantise (and the exact
+top-k keep mask for the sparse codecs) entirely on-chip, and writes the
+reconstructed row back.  HBM traffic is the floor: read D, write D.
+
+Row layout: a row of d_pad = 8 * C columns (d_pad a multiple of
+ROW_ALIGN = 8 * 128) is viewed as an (8, C) tile — the row-major reshape
+of the (rows, d_pad) matrix to (rows, 8, C), so flat column
+s * C + c sits at (s, c).  A (1, d_pad) block fills 1 of the 8 sublanes of
+every vreg and costs 8x its bytes in VMEM: on a v5e the topk codec's
+temporaries then exceed the scoped VMEM at the MNIST MLP's 156,800-wide
+leaf, and Mosaic refuses a (1, d_pad) block of a (rows, d_pad) array
+outright (last two block dims must be (8k, 128k) or the array's own).
+The (squeezed, 8, C) block of the (rows, 8, C) view fills every sublane;
+at that layout all three codecs compile for a v5e up to 2^19 columns
+(the VMEM limit and the ops wrapper's gate are in ops.py).  Every
+reduction below is order-free (max, integer counts), so the view changes
+no bit of the result.
 
 Top-k without a sort: |x| >= 0, so the f32 bit pattern reinterpreted as
 int32 is monotone in the float value (sign bit clear => signed compare ==
@@ -19,10 +33,10 @@ lowest-index-first (the `lax.top_k` contract) by a second MSB descent over
 the tied column indices.  ~2*31 vector passes over VMEM, zero HBM traffic
 beyond the single streaming read/write.
 
-Padding: rows are zero-padded to a lane multiple by the ops wrapper; a
-static `d_true` masks pad columns out of the abs-max and the top-k
-candidate pool (a pad key of -1 sorts below every valid key, so padding
-never steals a keep slot from a real element).
+Padding: rows are zero-padded to a ROW_ALIGN multiple here; a static
+`d_true` masks pad columns out of the abs-max and the top-k candidate
+pool (a pad key of -1 sorts below every valid key, so padding never
+steals a keep slot from a real element).
 """
 from __future__ import annotations
 
@@ -32,7 +46,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-LANES = 128  # lane-dim alignment for the (1, D) row blocks
+SUBLANES = 8
+LANES = 128
+ROW_ALIGN = SUBLANES * LANES  # a row is one (8, d_pad / 8) tile
 
 
 def _kth_largest(key: jax.Array, k: jax.Array | int, nbits: int) -> jax.Array:
@@ -48,9 +64,12 @@ def _kth_largest(key: jax.Array, k: jax.Array | int, nbits: int) -> jax.Array:
 
 
 def _codec_kernel(x_ref, out_ref, *, codec: str, k: int, d_true: int):
-    x = x_ref[...].astype(jnp.float32)                      # (1, d_pad)
-    d_pad = x.shape[-1]
-    col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    x = x_ref[...].astype(jnp.float32)                      # (8, C)
+    width = x.shape[-1]
+    d_pad = x.size
+    # flat column of element (s, c) of the row-major (8, C) row view
+    col = (jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) * width
+           + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1))
     valid = col < d_true
     absx = jnp.where(valid, jnp.abs(x), 0.0)
     if codec in ("quant8", "quant8_topk"):
@@ -81,24 +100,31 @@ def _codec_kernel(x_ref, out_ref, *, codec: str, k: int, d_true: int):
 def delta_codec_kernel(x: jax.Array, *, codec: str, k: int = 0,
                        d_true: int | None = None,
                        interpret: bool = False) -> jax.Array:
-    """Roundtrip each row of x (rows, d_pad) through `codec`.
+    """Roundtrip each row of x (rows, d) through `codec`.
 
-    d_pad % 128 == 0; columns >= d_true are padding (passed through the
-    quantiser but excluded from abs-max and top-k).  `k` is the static
-    per-row keep count for the sparse codecs.
+    Columns >= d_true (default d) are padding: passed through the
+    quantiser but excluded from abs-max and top-k.  Rows are zero-padded
+    to a ROW_ALIGN multiple for the (8, C) row view and sliced back, so
+    the result has x's shape.  `k` is the static per-row keep count for
+    the sparse codecs.
     """
-    rows, d_pad = x.shape
-    assert d_pad % LANES == 0, (d_pad, LANES)
+    rows, d = x.shape
     if d_true is None:
-        d_true = d_pad
-    assert 0 < d_true <= d_pad, (d_true, d_pad)
+        d_true = d
+    assert 0 < d_true <= d, (d_true, d)
+    pad = (-d) % ROW_ALIGN
+    xp = jnp.pad(x, ((0, 0), (0, pad))) if pad else x
+    width = (d + pad) // SUBLANES
 
     kernel = functools.partial(_codec_kernel, codec=codec, k=k, d_true=d_true)
-    return pl.pallas_call(
+    block = pl.BlockSpec((pl.Squeezed(), SUBLANES, width),
+                         lambda i: (i, 0, 0))
+    out = pl.pallas_call(
         kernel,
         grid=(rows,),
-        in_specs=[pl.BlockSpec((1, d_pad), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, d_pad), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        in_specs=[block],
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct((rows, SUBLANES, width), x.dtype),
         interpret=interpret,
-    )(x)
+    )(xp.reshape(rows, SUBLANES, width))
+    return out.reshape(rows, d + pad)[:, :d]

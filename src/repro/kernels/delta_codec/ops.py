@@ -7,11 +7,17 @@ matrix, roundtripped in one fused pass, and added back.  Per-leaf k for
 the sparse codecs follows the oracle's rule (`leaf_topk_k`), so results
 match `federated.compression` bitwise up to jit fusion of the final add.
 
-Routing: the Pallas kernel keeps a whole (1, d) row resident in VMEM, so
-it serves native-TPU backends for mid-size leaves; tiny leaves, oversize
-leaves, and non-TPU backends take the rowwise jnp ref — still one XLA
-fusion per leaf instead of the old multi-kernel chain (the interpret-mode
-emulation of the in-kernel MSB-descent select would be pure overhead).
+Routing: on TPU (`use_kernel=None`) every leaf of MIN_KERNEL_D to
+MAX_KERNEL_D columns runs the Pallas kernel, compiled natively.  The
+kernel keeps a whole row resident in VMEM as one (8, d/8) tile.  VMEM is
+the limit, found by compiling f32 rows of 3 clients for a v5e: all three
+codecs compile at 2^19 columns; at 2^20 quant8_topk is refused
+(RESOURCE_EXHAUSTED in vmem), at 2^21 all three are.  MAX_KERNEL_D =
+2^18 stays one halving below that and above the MNIST MLP's largest leaf
+(156,800); tests/test_tpu_compile.py compiles every codec at both sizes.
+Tiny leaves, leaves above the gate, and non-TPU backends take the rowwise
+jnp ref — one XLA fusion per leaf (the interpret-mode emulation of the
+in-kernel MSB-descent select would be pure overhead off-TPU).
 """
 from __future__ import annotations
 
@@ -21,14 +27,14 @@ from typing import Any
 
 import jax
 
-from repro.kernels import default_interpret, pad_to
-from repro.kernels.delta_codec.kernel import LANES, delta_codec_kernel
+from repro.kernels import default_interpret
+from repro.kernels.delta_codec.kernel import delta_codec_kernel
 from repro.kernels.delta_codec.ref import delta_codec_ref
 
 PyTree = Any
 
 MIN_KERNEL_D = 2048     # below this the ref fusion wins
-MAX_KERNEL_D = 1 << 18  # a (1, d) f32 row + select temporaries must fit VMEM
+MAX_KERNEL_D = 1 << 18  # VMEM fit on a v5e: see the module docstring
 
 
 @partial(jax.jit, static_argnames=("codec", "frac", "use_kernel",
@@ -62,8 +68,8 @@ def delta_codec_roundtrip(stacked: PyTree, params: PyTree, codec: str, *,
         delta = leaf.reshape(m, d) - ref_leaf.reshape(1, d)
         k = leaf_topk_k(d, frac) if codec != "quant8" else 0
         if use_kernel and MIN_KERNEL_D <= d <= MAX_KERNEL_D:
-            rt = delta_codec_kernel(pad_to(delta, LANES), codec=codec, k=k,
-                                    d_true=d, interpret=interpret)[:, :d]
+            rt = delta_codec_kernel(delta, codec=codec, k=k,
+                                    interpret=interpret)
         else:
             rt = delta_codec_ref(delta, codec, k=k)
         return (ref_leaf.reshape(1, d) + rt).reshape(leaf.shape)

@@ -2,9 +2,10 @@
 
 `prefix_avg(stacked_tree, perms, n_k)` flattens the stacked client pytree
 to one (M, D_leaf) matrix view per leaf, runs the Pallas kernel per leaf
-(or the jnp reference for small / off-TPU leaves), and rebuilds the R*M
-prefix-averaged models stacked on a leading flat walk-major axis — the
-exact model order the batched utility evaluator consumes
+(compiled natively on TPU, interpreted elsewhere; leaves narrower than
+one block, or `use_kernel=False`, take the jnp reference), and rebuilds
+the R*M prefix-averaged models stacked on a leading flat walk-major axis
+— the exact model order the batched utility evaluator consumes
 (`core/shapley_batched.gtg_shapley_streaming`).
 """
 from __future__ import annotations

@@ -1,9 +1,10 @@
 """jit'd public wrapper: pytree-aware batched subset averaging.
 
 `weighted_avg(stacked_tree, weights)` flattens the stacked client pytree to
-one (M, D_total) matrix view per leaf, runs the Pallas kernel per leaf (or
-the jnp reference off-TPU), and rebuilds R averaged pytrees stacked on a
-leading subset axis.
+one (M, D_total) matrix view per leaf, runs the Pallas kernel per leaf
+(compiled natively on TPU, interpreted elsewhere; leaves narrower than one
+block, or `use_kernel=False`, take the jnp reference), and rebuilds R
+averaged pytrees stacked on a leading subset axis.
 """
 from __future__ import annotations
 

@@ -1,14 +1,9 @@
-"""Version compatibility shims for the moving jax mesh/sharding APIs.
+"""AOT cost probes and sharding helpers for the installed jax (0.9).
 
-The ambient-mesh context manager has been renamed twice upstream
-(`jax.sharding.use_mesh` -> `jax.sharding.set_mesh` -> `jax.set_mesh`), and
-older releases (<= 0.4.x, as shipped in this container) have none of them —
-there the `Mesh` object itself is the context manager.  Likewise older
-`jax.jit` rejects bare `PartitionSpec`s in `in_shardings`/`out_shardings`;
-they must be wrapped into `NamedSharding`s by hand.
-
-Everything mesh-scoped in this repo goes through these two helpers so the
-code runs unchanged across jax versions.
+Everything that reads an AOT-compiled executable's cost or memory
+analysis goes through here, and `named_shardings` turns a pytree of
+PartitionSpecs into `NamedSharding`s for `jax.jit`.  Mesh contexts use
+`jax.set_mesh` directly.
 """
 from __future__ import annotations
 
@@ -20,81 +15,50 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 PyTree = Any
 
 
-def set_mesh(mesh):
-    """Context manager installing `mesh` as the ambient mesh.
-
-    Resolution order: `jax.set_mesh` -> `jax.sharding.set_mesh` ->
-    `jax.sharding.use_mesh` -> legacy `with mesh:` (the Mesh object is its
-    own context manager on jax <= 0.4.x).
-    """
-    for mod in (jax, jax.sharding):
-        for name in ("set_mesh", "use_mesh"):
-            fn = getattr(mod, name, None)
-            if fn is not None:
-                return fn(mesh)
-    return mesh
-
-
 def cost_analysis_of(compiled) -> dict:
-    """Normalised `cost_analysis()` of an AOT-compiled executable: a dict
-    with whatever of `flops` / `bytes_accessed` the backend reports (keys
-    absent when unavailable).  The raw API varies across jax versions/
-    backends (list-of-dicts on some, missing keys on others); everything
-    reading compiled costs goes through here."""
+    """`cost_analysis()` of an AOT-compiled executable as a dict with
+    whatever of `flops` / `bytes_accessed` the backend reports (keys
+    absent when unavailable or NaN)."""
+    cost = compiled.cost_analysis() or {}
     out: dict = {}
-    try:
-        cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0]
-        for key, name in (("flops", "flops"),
-                          ("bytes accessed", "bytes_accessed")):
-            v = cost.get(key)
-            if v is not None and v == v:
-                out[name] = float(v)
-    except Exception:
-        pass
+    for key, name in (("flops", "flops"),
+                      ("bytes accessed", "bytes_accessed")):
+        v = cost.get(key)
+        if v is not None and v == v:
+            out[name] = float(v)
     return out
 
 
 def memory_stats_of(compiled):
-    """Normalised `memory_analysis()` of an AOT-compiled executable: byte
-    counts plus a derived `peak_bytes` = temp + argument + output −
-    aliased, or None when the backend/version exposes no analysis (some
-    CPU builds)."""
-    try:
-        mem = compiled.memory_analysis()
-        sizes = {}
-        for name in ("temp", "argument", "output", "alias",
-                     "generated_code"):
-            v = getattr(mem, f"{name}_size_in_bytes", None)
-            if v is not None:
-                sizes[f"{name}_bytes"] = int(v)
-        if not sizes:
-            return None
-        peak = (sizes.get("temp_bytes", 0) + sizes.get("argument_bytes", 0)
-                + sizes.get("output_bytes", 0) - sizes.get("alias_bytes", 0))
-        sizes["peak_bytes"] = max(int(peak), 0)
-        return sizes
-    except Exception:
+    """`memory_analysis()` of an AOT-compiled executable: byte counts
+    plus a derived `peak_bytes` = temp + argument + output − aliased, or
+    None when the backend exposes no analysis."""
+    mem = compiled.memory_analysis()
+    if mem is None:
         return None
+    sizes = {f"{name}_bytes": int(getattr(mem, f"{name}_size_in_bytes"))
+             for name in ("temp", "argument", "output", "alias",
+                          "generated_code")}
+    peak = (sizes["temp_bytes"] + sizes["argument_bytes"]
+            + sizes["output_bytes"] - sizes["alias_bytes"])
+    sizes["peak_bytes"] = max(peak, 0)
+    return sizes
 
 
 def aot_compile(jitted, *args, **kwargs):
     """`jitted.lower(*args).compile()`, None on failure.  Array arguments
     are reduced to their avals first, so the probe works on donated/
     deleted buffers and never touches data."""
-    import jax as _jax
-
     def aval(a):
         if hasattr(a, "shape") and hasattr(a, "dtype"):
-            return _jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                         sharding=getattr(a, "sharding",
-                                                          None))
+            return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                        sharding=getattr(a, "sharding",
+                                                         None))
         return a
 
     try:
-        args = _jax.tree.map(aval, args)
-        kwargs = _jax.tree.map(aval, kwargs)
+        args = jax.tree.map(aval, args)
+        kwargs = jax.tree.map(aval, kwargs)
         return jitted.lower(*args, **kwargs).compile()
     except Exception:
         return None
@@ -123,11 +87,8 @@ def compiled_memory_stats(jitted, *args, **kwargs):
 
 def named_shardings(mesh, specs: PyTree) -> PyTree:
     """Normalise a pytree of PartitionSpec / None / Sharding leaves into
-    `NamedSharding`s on `mesh` (None -> fully replicated).
-
-    `jax.jit` on older versions only accepts concrete `Sharding`s; newer
-    versions accept raw specs under an ambient mesh, where this wrapping is
-    a harmless no-op semantically.
+    `NamedSharding`s on `mesh` (None -> fully replicated), so `jax.jit`
+    gets concrete shardings with or without an ambient mesh.
     """
     def conv(s):
         if s is None:

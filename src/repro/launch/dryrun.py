@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, get_config
-from repro.launch.compat import named_shardings, set_mesh
+from repro.launch.compat import named_shardings
 from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import (
     assembled_roofline, collective_bytes_from_text, roofline_report,
@@ -118,7 +118,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
 
     t0 = time.time()
     fn, args, in_s, out_s = build_step(cfg, shape, mesh)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn, in_shardings=named_shardings(mesh, in_s),
                           out_shardings=named_shardings(mesh, out_s)
                           ).lower(*args)
@@ -145,7 +145,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
         "collective_bytes_toplevel": coll,
     }
     if assemble:
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             rec["assembled"] = assembled_roofline(cfg, shape, mesh)
         rec["roofline"] = roofline_report(cfg, shape, rec,
                                           n_devices=int(mesh.devices.size))

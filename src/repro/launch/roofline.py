@@ -1,14 +1,12 @@
 """Roofline analysis from compiled dry-run artifacts.
 
-TPU v5e hardware constants (per chip):
-    peak bf16 compute   197 TFLOP/s
-    HBM bandwidth       819 GB/s
-    ICI per link        ~50 GB/s
+Per-chip peaks come from `PEAKS`, keyed by `device.device_kind`; a kind
+that is not in the table is an error, never a default.  The dry-run
+roofline projects onto the production target, a TPU v5e:
 
-Three terms per (arch x shape x mesh):
-    compute    = FLOPs_per_device / 197e12
-    memory     = bytes_per_device / 819e9
-    collective = collective_traffic_per_device / 50e9
+    compute    = FLOPs_per_device / peak bf16 FLOP/s
+    memory     = bytes_per_device / HBM bytes/s
+    collective = collective_traffic_per_device / ICI bytes/s per link
 
 Methodology notes:
   * ``compiled.cost_analysis()`` runs on the post-SPMD per-device module, so
@@ -31,13 +29,33 @@ from __future__ import annotations
 
 import dataclasses
 import re
+from typing import NamedTuple
 
 import jax
 import numpy as np
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+
+class Peaks(NamedTuple):
+    flops: float        # peak bf16 FLOP/s per chip
+    hbm_bw: float       # HBM bytes/s per chip
+    ici_bw: float       # inter-chip bytes/s per link
+
+
+# Source: Google Cloud documentation, "TPU v5e" (system architecture):
+# 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of ICI per chip over four
+# links (50 GB/s per link).  JAX reports a v5e as "TPU v5 lite".
+V5E = "TPU v5 lite"
+PEAKS = {V5E: Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9)}
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    """Published peaks of one chip of `device_kind`; KeyError if unknown."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -170,9 +188,10 @@ def model_flops(cfg, shape) -> float:
 
 def roofline_report(cfg, shape, rec: dict, *, n_devices: int) -> dict:
     asm = rec["assembled"]
-    compute_t = asm["per_device_flops"] / PEAK_FLOPS
-    memory_t = asm["per_device_bytes"] / HBM_BW
-    coll_t = asm["per_device_collective_bytes"] / ICI_BW
+    peak = PEAKS[V5E]          # the dry run's production target
+    compute_t = asm["per_device_flops"] / peak.flops
+    memory_t = asm["per_device_bytes"] / peak.hbm_bw
+    coll_t = asm["per_device_collective_bytes"] / peak.ici_bw
     terms = {"compute_s": compute_t, "memory_s": memory_t,
              "collective_s": coll_t}
     dominant = max(terms, key=terms.get)
@@ -186,6 +205,7 @@ def roofline_report(cfg, shape, rec: dict, *, n_devices: int) -> dict:
         "useful_flops_ratio": mf / hlo_global if hlo_global else 0.0,
         "step_time_lower_bound_s": max(terms.values()),
         "flops_util_at_bound": (
-            asm["per_device_flops"] / PEAK_FLOPS / max(max(terms.values()), 1e-12)),
+            asm["per_device_flops"] / peak.flops
+            / max(max(terms.values()), 1e-12)),
     }
     return report

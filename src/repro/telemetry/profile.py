@@ -7,12 +7,14 @@ The PR-6 event stream records *when* things happened; this module records
     unified (launch.compat `cost_analysis_of` / `memory_stats_of` plus
     the roofline terms of launch.roofline): compiled flops, bytes
     accessed, the XLA memory-analysis byte classes with derived
-    `peak_bytes`, arithmetic intensity (flops / bytes accessed), and the
-    v5e-normalised roofline split (compute-bound vs memory-bound seconds;
-    `cost_analysis()` runs on the post-SPMD module, so every figure is
-    per-device).  `cached_cost_card` memoises by (executable, arg avals)
-    — the engines call it on every run but a warm executable re-pays
-    nothing, keeping the BENCH_telemetry host-overhead gate honest.
+    `peak_bytes`, arithmetic intensity (flops / bytes accessed), and, on
+    a TPU, the roofline split against that chip's published peaks
+    (compute-bound vs memory-bound seconds; a CPU has no published peaks
+    and its cards carry no roofline block; `cost_analysis()` runs on the
+    post-SPMD module, so every figure is per-device).  `cached_cost_card`
+    memoises by (executable, arg avals) — the engines call it on every
+    run but a warm executable re-pays nothing, keeping the
+    BENCH_telemetry host-overhead gate honest.
     Engines attach the card to their `compile` telemetry events, so the
     JSONL stream answers "which stage burns the flops/bytes" without a
     profiler in the loop.
@@ -47,10 +49,7 @@ import jax
 from repro.launch.compat import aot_compile, cost_analysis_of, memory_stats_of
 from repro.telemetry.trace import SPAN_PREFIX, record_spans
 
-# v5e roofline constants (launch.roofline is the source of truth); the
-# card's roofline block normalises per-device cost against this target
-# part even off-TPU, so trajectory comparisons are hardware-stable.
-from repro.launch.roofline import HBM_BW, PEAK_FLOPS
+from repro.launch.roofline import peaks_for
 
 
 def cost_card_of_compiled(compiled) -> Optional[dict]:
@@ -65,14 +64,18 @@ def cost_card_of_compiled(compiled) -> Optional[dict]:
     bytes_acc = card.get("bytes_accessed")
     if flops is not None and bytes_acc:
         card["intensity_flops_per_byte"] = flops / bytes_acc
-    if flops is not None or bytes_acc is not None:
-        compute_s = (flops or 0.0) / PEAK_FLOPS
-        memory_s = (bytes_acc or 0.0) / HBM_BW
+    device = jax.devices()[0]
+    if device.platform != "cpu" and (flops is not None
+                                     or bytes_acc is not None):
+        peak = peaks_for(device.device_kind)
+        compute_s = (flops or 0.0) / peak.flops
+        memory_s = (bytes_acc or 0.0) / peak.hbm_bw
         card["roofline"] = {
+            "device_kind": device.device_kind,
             "compute_s": compute_s,
             "memory_s": memory_s,
             "dominant": "compute" if compute_s >= memory_s else "memory",
-            "ridge_intensity_flops_per_byte": PEAK_FLOPS / HBM_BW,
+            "ridge_intensity_flops_per_byte": peak.flops / peak.hbm_bw,
         }
     return card
 

@@ -232,3 +232,40 @@ def test_segment_retry_bounded(monkeypatch):
     monkeypatch.setattr(segments, "jitted_segment_step", flaky_factory(2))
     with pytest.raises(RuntimeError, match="transient"):
         run_grid(spec, retries=1, isolate_cells=False)
+
+
+def test_segment_retry_after_donated_carry(monkeypatch):
+    """A dispatch that fails after consuming its (donated) carry is
+    retried from a copy taken before the launch: the retry never
+    re-dispatches deleted buffers, and the run matches a clean one."""
+    import repro.grid.segments as segments
+    from repro.grid import run_grid
+
+    spec = _tiny_grid_spec()
+    real = segments.jitted_segment_step
+    state = {"left": 1}
+
+    def factory(model, ccfg, seg_spec, vmapped=False):
+        step = real(model, ccfg, seg_spec, vmapped=vmapped)
+
+        def wrapped(carry, *rest):
+            if state["left"] > 0:
+                state["left"] -= 1
+                # what donation does to the carry on TPU/GPU
+                for leaf in jax.tree.leaves(carry):
+                    leaf.delete()
+                raise RuntimeError("failed after launch")
+            return step(carry, *rest)
+
+        return wrapped
+
+    clean = run_grid(spec)
+    monkeypatch.setattr(segments, "jitted_segment_step", factory)
+    retried = run_grid(spec, retries=1, isolate_cells=False)
+    assert state["left"] == 0
+    for a, b in zip(clean.results, retried.results):
+        assert [list(r) for r in a.selections] == \
+            [list(r) for r in b.selections]
+        for la, lb in zip(jax.tree.leaves(a.params),
+                          jax.tree.leaves(b.params)):
+            np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))

@@ -66,7 +66,8 @@ def test_weighted_avg_subset_masks_recover_members(key):
 
 # -------------------------------------------------------- prefix_avg ------
 @pytest.mark.parametrize("m,d,r", [(3, 2048, 4), (5, 4096, 7),
-                                   (8, 2048, 16), (20, 2048, 11)])
+                                   (8, 2048, 16), (20, 2048, 11),
+                                   (3, 4096, 150)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_prefix_avg_kernel_matches_ref(m, d, r, dtype, key):
     stacked = jax.random.normal(key, (m, d), dtype)
@@ -151,7 +152,8 @@ def test_ce_loss_wrapper_handles_unaligned_vocab(key):
 # A gather copies bits, so every comparison below is exact equality —
 # including bf16 and repeated/boundary ids.
 @pytest.mark.parametrize("n,d,m", [(7, 2048, 3), (16, 4096, 5),
-                                   (100, 2048, 20), (33, 6144, 8)])
+                                   (100, 2048, 20), (33, 6144, 8),
+                                   (300, 4096, 3)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_cohort_gather_kernel_matches_ref(n, d, m, dtype, key):
     table = jax.random.normal(key, (n, d), dtype)
@@ -256,6 +258,23 @@ def test_delta_codec_topk_tie_semantics(key):
         want = _jit_ref(x, codec="topk", k=k)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
         assert int(jnp.count_nonzero(got[1])) == k
+
+
+def test_delta_codec_tie_break_follows_flat_column_order(key):
+    """The kernel sees a row as an (8, d_pad/8) tile; ties must still
+    resolve by flat column, lowest first.  d = 5000 is not a multiple of
+    the 1024-wide row alignment (5120 padded, 640 columns per sublane);
+    the tied columns sit in sublanes 0, 1, 2 and 4 in an order where the
+    within-sublane column alone would rank them differently."""
+    d = 5000
+    cols = [5, 600, 1100, 3000]       # (s, c): (0,5) (0,600) (1,460) (4,440)
+    x = jnp.zeros((2, d)).at[:, cols].set(3.0)
+    x = x.at[1, 4999].set(-7.0)       # the last real column, in sublane 7
+    for k in (1, 2, 3, 4):
+        got = delta_codec_kernel(x, codec="topk", k=k, interpret=True)
+        want = _jit_ref(x, codec="topk", k=k)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
+                                      err_msg=f"k={k}")
 
 
 def test_delta_codec_zero_rows(key):

@@ -89,3 +89,53 @@ def test_tuned_configs_apply_perf_overrides():
     assert base.parallelism == "tp", "baseline must stay paper-literal"
     for arch in TUNED_OVERRIDES:
         get_config(arch, tuned=True)  # all resolvable
+
+
+_CACHE_PROBE = """
+import os, sys, jax, jax.numpy as jnp
+import repro.launch.compile_cache as cc
+cc.REPO_CACHE = sys.argv[1]          # stand-in for <checkout>/.jax_cache
+print("DIR", cc.use_compile_cache())
+jax.jit(lambda x: x * 3 + 1)(jnp.ones(7)).block_until_ready()
+"""
+
+
+@pytest.mark.parametrize("env_set", [True, False])
+def test_compile_cache_lands_only_in_its_one_directory(tmp_path, env_set):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the only place entries
+    land; unset, they land only in the fixed in-checkout directory."""
+    import os
+    import subprocess
+    import sys
+
+    from repro.launch import compile_cache
+
+    env_dir, repo_dir = tmp_path / "env", tmp_path / "repo"
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_set:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+    p = subprocess.run([sys.executable, "-c", _CACHE_PROBE, str(repo_dir)],
+                       env=env, capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0, p.stderr
+    used, unused = (env_dir, repo_dir) if env_set else (repo_dir, env_dir)
+    assert f"DIR {used}" in p.stdout
+    assert any(used.iterdir()) and not unused.exists()
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.REPO_CACHE == os.path.join(root, ".jax_cache")
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+def test_peaks_keyed_by_device_kind():
+    """v5e peaks are the published ones; an unknown kind is an error."""
+    from repro.launch.roofline import peaks_for
+
+    v5e = peaks_for("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks_for("TPU v9 imaginary")

@@ -43,9 +43,14 @@ def test_cost_card_populated_and_cached():
     assert card["peak_bytes"] is not None and card["peak_bytes"] > 0
     assert card["intensity_flops_per_byte"] == pytest.approx(
         card["flops"] / card["bytes_accessed"])
-    roof = card["roofline"]
-    assert roof["dominant"] in ("compute", "memory")
-    assert roof["compute_s"] >= 0 and roof["memory_s"] >= 0
+    # roofline terms only against a chip's published peaks: a CPU card
+    # carries none (launch.roofline.PEAKS)
+    if jax.devices()[0].platform == "cpu":
+        assert "roofline" not in card
+    else:
+        roof = card["roofline"]
+        assert roof["dominant"] in ("compute", "memory")
+        assert roof["compute_s"] >= 0 and roof["memory_s"] >= 0
     again = cached_cost_card(f, x, x)
     third = cached_cost_card(f, x, x)
     assert again is third                     # dict lookup, no recompile
@@ -73,7 +78,10 @@ def test_scan_compile_event_carries_cost_card():
     card = compile_ev["cost_card"]
     assert card["flops"] > 0 and card["bytes_accessed"] > 0
     assert card["peak_bytes"] > 0
-    assert card["roofline"]["dominant"] in ("compute", "memory")
+    if jax.devices()[0].platform == "cpu":
+        assert "roofline" not in card
+    else:
+        assert card["roofline"]["dominant"] in ("compute", "memory")
 
 
 def test_grid_cost_cards_and_heartbeat_peak(tmp_path):

@@ -30,7 +30,7 @@ import dataclasses, jax
 from repro.configs import get_config
 from repro.launch.shapes import InputShape, pad_vocab
 from repro.launch import dryrun as DR
-from repro.launch.compat import named_shardings, set_mesh
+from repro.launch.compat import named_shardings
 from repro.launch.mesh import make_debug_mesh
 from repro.launch.sharding import launch_cfg
 
@@ -52,7 +52,7 @@ for arch in ["tinyllama_1_1b", "qwen3_moe_30b_a3b", "mamba2_370m",
     for shape in shapes:
         cfg = launch_cfg(pad_vocab(c0), mesh, shape)
         fn, args, in_s, out_s = DR.build_step(cfg, shape, mesh)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jax.jit(fn, in_shardings=named_shardings(mesh, in_s),
                     out_shardings=named_shardings(mesh, out_s)
                     ).lower(*args).compile()
@@ -70,7 +70,6 @@ import dataclasses, jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_config
 from repro.launch.shapes import InputShape, pad_vocab
 from repro.launch import dryrun as DR
-from repro.launch.compat import set_mesh
 from repro.launch.mesh import make_debug_mesh
 from repro.launch.sharding import launch_cfg
 from repro.models.lm import model as M
@@ -85,7 +84,7 @@ loss_single = float(M.loss_fn(c0, params, batch))
 mesh = make_debug_mesh((2, 4), ("data", "model"))
 shape = InputShape("t", 64, 8, "train")
 cfg = launch_cfg(c0, mesh, shape)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     loss_sharded = float(jax.jit(lambda p, b: M.loss_fn(cfg, p, b))(params, batch))
 print("SINGLE", loss_single, "SHARDED", loss_sharded)
 assert abs(loss_single - loss_sharded) < 1e-3, (loss_single, loss_sharded)
@@ -102,7 +101,7 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.federated.client import ClientConfig
 from repro.federated.sim import parallel_client_round
-from repro.launch.compat import named_shardings, set_mesh
+from repro.launch.compat import named_shardings
 from repro.launch.mesh import make_debug_mesh
 from repro.models.mlp_cnn import make_mlp
 
@@ -119,7 +118,7 @@ ek = jnp.full((M_sel,), 1)
 sg = jnp.zeros((M_sel,))
 keys = jax.random.split(key, M_sel)
 
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     fn = jax.jit(lambda *a: parallel_client_round(model, ccfg, *a),
                  in_shardings=named_shardings(
                      mesh, (None, P("data"), P("data"), P("data"),
