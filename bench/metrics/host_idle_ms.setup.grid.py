@@ -1,0 +1,10 @@
+"""`host_idle_ms.setup` of the grid cell, where it moves
+`grid_rounds_per_s`: the same reading as `host_idle_ms.setup.py` (one
+`setup_run` per grid cell)."""
+import os
+
+from bench.harness import load_module
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+read = load_module(os.path.join(HERE, "host_idle_ms.setup.py"),
+                   "bench_metric_host_idle_ms_setup_base").read

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from repro.federated.client import ClientConfig, client_update
 from repro.kernels.cohort_gather import cohort_take
 from repro.models.mlp_cnn import ClassifierModel
+from repro.telemetry.trace import named_stage
 
 PyTree = Any
 
@@ -71,10 +72,13 @@ def cohort_update(
     """
     m = sel.shape[0]
     ckeys = jax.random.split(round_key, m + 1)
-    xs = cohort_take(xs_all, sel, axis_name=client_axis)
-    ys = cohort_take(ys_all, sel, axis_name=client_axis)
-    nv = cohort_take(nv_all, sel, axis_name=client_axis)
-    sg = cohort_take(sigma_all, sel, axis_name=client_axis)
+    # its own scope (a sub-stage, inside the caller's `repro.train`), so a
+    # device profile tells the gather's time from local SGD's
+    with named_stage("gather"):
+        xs = cohort_take(xs_all, sel, axis_name=client_axis)
+        ys = cohort_take(ys_all, sel, axis_name=client_axis)
+        nv = cohort_take(nv_all, sel, axis_name=client_axis)
+        sg = cohort_take(sigma_all, sel, axis_name=client_axis)
     stacked = batched_client_update(model, ccfg, params, xs, ys, nv,
                                     epochs_k, sg, ckeys[:m])
     return stacked, nv.astype(jnp.float32), ckeys[m]

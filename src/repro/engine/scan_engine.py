@@ -239,26 +239,26 @@ def _run_scan_sharded(cfg, s, spec, t_start, *, telemetry, ctimer):
     Bit-identical to the dense scan at equal config (DESIGN.md §16)."""
     from repro.grid.segments import run_segments
     from repro.grid.shard import make_run_mesh, unpad_scan_output
-    from repro.telemetry.profile import trace_capture
+    from repro.telemetry.trace import stage
 
     spec_sel = s.sel_spec
     # deterministic rebuild of the mesh setup_run sharded the data on
     # (Mesh is hashable/comparable, so the step cache keys correctly)
     mesh = make_run_mesh(1, cfg.clients_shards)
-    with ctimer:
+    with ctimer, stage("operands"):
         batch = _sharded_scan_batch(cfg, s, mesh)
-    with trace_capture(telemetry, label="run_scan_client_sharded"):
-        out_b, report = run_segments(s.model, cfg.client, spec, batch,
-                                     mesh=mesh, telemetry=telemetry)
-    out_b = unpad_scan_output(out_b, cfg.n_clients)
-    out = jax.tree.map(lambda x: x[0], out_b)
-
-    res = results_from_scan(cfg, s, out,
-                            wall_time_s=time.perf_counter() - t_start,
-                            seed=cfg.seed, dispatches=report.n_segments,
-                            uses_shapley=spec_sel.uses_shapley,
-                            compile_time_s=(ctimer.seconds
-                                            + report.compile_time_s))
+    out_b, report = run_segments(s.model, cfg.client, spec, batch,
+                                 mesh=mesh, telemetry=telemetry)
+    with stage("results"):
+        out_b = unpad_scan_output(out_b, cfg.n_clients)
+        out = jax.tree.map(lambda x: x[0], out_b)
+        res = results_from_scan(cfg, s, out,
+                                wall_time_s=time.perf_counter() - t_start,
+                                seed=cfg.seed,
+                                dispatches=report.n_segments,
+                                uses_shapley=spec_sel.uses_shapley,
+                                compile_time_s=(ctimer.seconds
+                                                + report.compile_time_s))
     if telemetry is not None:
         from repro.telemetry.metrics import emit_scan_rounds, run_end_payload
         telemetry.emit("compile", seconds=res.compile_time_s,
@@ -299,8 +299,9 @@ def run_federated_scan(cfg, s, t_start: float, *, telemetry=None,
     dispatch (host-side, §15), and the compile event carries the scan
     executable's cost card (§17); `telemetry.live_tap` additionally
     selects the tap-carrying executable and routes its in-scan
-    callbacks, and `telemetry.trace_dir` wraps the dispatch in a
-    profiler capture window.
+    callbacks; with `telemetry.trace_dir` (a capture window that
+    `run_federated` opened) the dispatch is waited on inside its span.
+    Host spans: `repro.operands`, `repro.scan`, `repro.results`.
     """
     from repro.telemetry.trace import CompileTimer, live_sink, stage
 
@@ -315,28 +316,29 @@ def run_federated_scan(cfg, s, t_start: float, *, telemetry=None,
         return _run_scan_sharded(cfg, s, spec, t_start,
                                  telemetry=telemetry, ctimer=ctimer)
     spec = make_scan_spec(cfg, (spec_sel,), live_tap=live)
+    capturing = bool(telemetry is not None and telemetry.trace_dir)
 
-    from repro.telemetry.profile import trace_capture
-
-    operands = scan_operands(cfg, s)
-    with ctimer, trace_capture(telemetry, label="run_scan") as capturing:
+    with stage("operands"):
+        operands = scan_operands(cfg, s)
+    with ctimer:
         run = jitted_run_scan(s.model, cfg.client, spec)
         with live_sink(telemetry if live else None), stage("scan"):
             # s.params is donated on TPU/GPU: nothing below may read it;
             # out.params has the same avals where shapes are needed
             out = run(s.params, *operands)
-            if live or capturing is not None:
+            if live or capturing:
                 # drain the in-scan debug callbacks before the sink
                 # detaches — taps must land inside the run's stream —
                 # and keep capture-window spans covering execution, not
                 # just the dispatch enqueue
                 jax.block_until_ready(out.params)
 
-    res = results_from_scan(cfg, s, out,
-                            wall_time_s=time.perf_counter() - t_start,
-                            seed=cfg.seed, dispatches=1,
-                            uses_shapley=spec_sel.uses_shapley,
-                            compile_time_s=ctimer.seconds)
+    with stage("results"):
+        res = results_from_scan(cfg, s, out,
+                                wall_time_s=time.perf_counter() - t_start,
+                                seed=cfg.seed, dispatches=1,
+                                uses_shapley=spec_sel.uses_shapley,
+                                compile_time_s=ctimer.seconds)
     if telemetry is not None:
         from repro.telemetry.metrics import emit_scan_rounds, run_end_payload
         from repro.telemetry.profile import cached_cost_card

@@ -248,6 +248,14 @@ def setup_run(cfg: FLConfig, data: Optional[SynthDataset] = None,
     rng/key streams and every derived value are unchanged (the stacks just
     gain zero pad rows that nothing reads).
     """
+    from repro.telemetry.trace import stage
+
+    with stage("setup"):
+        return _setup_run(cfg, data, model, client_mesh)
+
+
+def _setup_run(cfg: FLConfig, data: Optional[SynthDataset],
+               model: Optional[ClassifierModel], client_mesh) -> RunSetup:
     rng = np.random.default_rng(cfg.seed)
     key = jax.random.key(cfg.seed)
 
@@ -384,7 +392,10 @@ def run_federated(cfg: FLConfig, data: Optional[SynthDataset] = None,
     """Drive one federated run; `telemetry` (repro.telemetry.Telemetry)
     opts into the structured event stream of DESIGN.md §15 — the default
     None path adds zero dispatches and leaves every output bit-identical.
+    With `telemetry.trace_dir` set, one profiler capture window (§17)
+    spans set-up and the rounds, so it holds the `repro.setup` span too.
     """
+    from repro.telemetry.profile import trace_capture
     from repro.telemetry.trace import CompileTimer
 
     t_start = time.perf_counter()
@@ -404,19 +415,30 @@ def run_federated(cfg: FLConfig, data: Optional[SynthDataset] = None,
         from repro.launch.mesh import make_run_mesh
         client_mesh = make_run_mesh(1, cfg.clients_shards)
     ctimer = CompileTimer()
-    with ctimer:
-        s = setup_run(cfg, data, model, client_mesh=client_mesh)
-    if telemetry is not None:
-        from repro.telemetry.events import provenance
-        telemetry.emit("run_start", run_id=telemetry.run_id, kind="solo",
-                       engine=cfg.engine, selector=cfg.selector,
-                       n_clients=cfg.n_clients, m=cfg.m,
-                       rounds=cfg.rounds, seed=cfg.seed,
-                       eval_every=cfg.eval_every, provenance=provenance())
-    if cfg.engine == "scan":
-        from repro.engine.scan_engine import run_federated_scan
-        return run_federated_scan(cfg, s, t_start, telemetry=telemetry,
-                                  ctimer=ctimer)
+    label = f"run_{cfg.engine}" + ("_client_sharded"
+                                   if client_mesh is not None else "")
+    with trace_capture(telemetry, label=label):
+        with ctimer:
+            s = setup_run(cfg, data, model, client_mesh=client_mesh)
+        if telemetry is not None:
+            from repro.telemetry.events import provenance
+            telemetry.emit("run_start", run_id=telemetry.run_id,
+                           kind="solo", engine=cfg.engine,
+                           selector=cfg.selector, n_clients=cfg.n_clients,
+                           m=cfg.m, rounds=cfg.rounds, seed=cfg.seed,
+                           eval_every=cfg.eval_every,
+                           provenance=provenance())
+        if cfg.engine == "scan":
+            from repro.engine.scan_engine import run_federated_scan
+            return run_federated_scan(cfg, s, t_start, telemetry=telemetry,
+                                      ctimer=ctimer)
+        return _run_host_rounds(cfg, s, t_start, telemetry=telemetry,
+                                ctimer=ctimer)
+
+
+def _run_host_rounds(cfg: FLConfig, s: RunSetup, t_start: float, *,
+                     telemetry, ctimer) -> FLResult:
+    """The loop and batched engines: each round driven from the host."""
     model, params, key = s.model, s.params, s.key
     sel_spec, sstate = s.sel_spec, s.sel_state
     dev_select, dev_update = jitted_selector(sel_spec)

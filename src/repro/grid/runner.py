@@ -172,6 +172,7 @@ def run_grid(spec: GridSpec, *, data=None, model=None,
       the checkpoint fingerprint — and resumability — are unaffected.
     """
     from repro.engine.scan_engine import make_scan_spec, results_from_scan
+    from repro.federated.compression import codec_nbytes
     from repro.federated.server import setup_run
     from repro.grid.shard import (
         CLIENT_AXIS, make_run_mesh, pad_batch_clients, unpad_scan_output,
@@ -192,36 +193,41 @@ def run_grid(spec: GridSpec, *, data=None, model=None,
         cell_data = list(data)
     else:
         cell_data = [data] * len(cfgs)
-    setups = [setup_run(c, d, model) for c, d in zip(cfgs, cell_data)]
-    model = setups[0].model
-    sel_specs = [s.sel_spec for s in setups]
-    # the codec joins the partition key: it is jit-static inside the round
-    # body, so each codec group gets its own executable (DESIGN.md §18)
-    partitions = partition_cells(sel_specs,
-                                 [c.upload_codec for c in cfgs])
-
-    if checkpoint_dir:
-        _check_fingerprint(checkpoint_dir, spec, rounds_per_segment,
-                           resume)
-
-    if telemetry is not None:
-        from repro.telemetry.events import provenance
-        from repro.telemetry.metrics import run_end_payload
-        telemetry.emit(
-            "run_start", run_id=telemetry.run_id, kind="grid",
-            cells=len(cfgs), partitions=len(partitions),
-            rounds=spec.base.rounds, rounds_per_segment=rounds_per_segment,
-            checkpoint_dir=checkpoint_dir, provenance=provenance())
-
+    from repro.telemetry.metrics import emit_scan_rounds, run_end_payload
     from repro.telemetry.profile import trace_capture
+    from repro.telemetry.trace import stage
 
-    per_partition: list = []
-    reports: list = []
-    n_segments = 1
-    compile_s = 0.0
-    peaks: list = []   # per-partition compiled peak bytes (compile_stats)
-    cards: list = []   # per-partition step cost cards (telemetry.profile)
+    # one capture window (telemetry.trace_dir) over the cells' set-ups
+    # and every partition, so it holds the `repro.setup` spans too
     with trace_capture(telemetry, label="grid"):
+        setups = [setup_run(c, d, model) for c, d in zip(cfgs, cell_data)]
+        model = setups[0].model
+        sel_specs = [s.sel_spec for s in setups]
+        # the codec joins the partition key: it is jit-static inside the
+        # round body, so each codec group gets its own executable
+        # (DESIGN.md §18)
+        partitions = partition_cells(sel_specs,
+                                     [c.upload_codec for c in cfgs])
+
+        if checkpoint_dir:
+            _check_fingerprint(checkpoint_dir, spec, rounds_per_segment,
+                               resume)
+
+        if telemetry is not None:
+            from repro.telemetry.events import provenance
+            telemetry.emit(
+                "run_start", run_id=telemetry.run_id, kind="grid",
+                cells=len(cfgs), partitions=len(partitions),
+                rounds=spec.base.rounds,
+                rounds_per_segment=rounds_per_segment,
+                checkpoint_dir=checkpoint_dir, provenance=provenance())
+
+        per_partition: list = []
+        reports: list = []
+        n_segments = 1
+        compile_s = 0.0
+        peaks: list = []   # per-partition compiled peak bytes
+        cards: list = []   # per-partition step cost cards (§17)
         for pi, part in enumerate(partitions):
             t_part = time.perf_counter()
             try:
@@ -236,11 +242,12 @@ def run_grid(spec: GridSpec, *, data=None, model=None,
                     client_axis=CLIENT_AXIS if client_sharded
                     else None)._replace(
                         rounds_per_segment=rounds_per_segment)
-                batch = _build_batch(part, cfgs, setups, sel_specs,
-                                     spec.base.rounds)
-                if client_sharded:
-                    batch = pad_batch_clients(batch,
-                                              spec.base.clients_shards)
+                with stage("operands"):
+                    batch = _build_batch(part, cfgs, setups, sel_specs,
+                                         spec.base.rounds)
+                    if client_sharded:
+                        batch = pad_batch_clients(batch,
+                                                  spec.base.clients_shards)
                 if telemetry is not None:
                     telemetry.heartbeat(
                         f"partition {pi + 1}/{len(partitions)} "
@@ -263,37 +270,37 @@ def run_grid(spec: GridSpec, *, data=None, model=None,
                             "dispatched); checkpoints are the resume "
                             "point", force=True)
                     return None
-                if client_sharded:
-                    out = unpad_scan_output(out, spec.base.n_clients)
-                n_segments = report.n_segments
-                # the partition's cells ran fused: they share ITS duration
-                # (not the grid's running total, which would bill later
-                # partitions for earlier ones' work)
-                wall = time.perf_counter() - t_part
-                results = []
-                evals_total = 0
-                for j, idx in enumerate(part.cell_indices):
-                    out_j = jax.tree.map(lambda x: x[j], out)
-                    res = results_from_scan(
-                        cfgs[idx], setups[idx], out_j, wall_time_s=wall,
-                        seed=cfgs[idx].seed, dispatches=report.n_segments,
-                        uses_shapley=part.key.needs_sv,
-                        compile_time_s=report.compile_time_s)
-                    evals_total += res.shapley_evals
-                    results.append(res)
-                    if telemetry is not None:
-                        from repro.engine.schedule import eval_mask as _emask
-                        from repro.federated.compression import codec_nbytes
-                        from repro.telemetry.metrics import emit_scan_rounds
-                        emit_scan_rounds(
-                            telemetry, out_j,
+                with stage("results"):
+                    if client_sharded:
+                        out = unpad_scan_output(out, spec.base.n_clients)
+                    n_segments = report.n_segments
+                    # the partition's cells ran fused: they share ITS
+                    # duration (not the grid's running total, which would
+                    # bill later partitions for earlier ones' work)
+                    wall = time.perf_counter() - t_part
+                    results = []
+                    evals_total = 0
+                    for j, idx in enumerate(part.cell_indices):
+                        out_j = jax.tree.map(lambda x: x[j], out)
+                        res = results_from_scan(
+                            cfgs[idx], setups[idx], out_j, wall_time_s=wall,
+                            seed=cfgs[idx].seed,
+                            dispatches=report.n_segments,
                             uses_shapley=part.key.needs_sv,
-                            codec_bytes=codec_nbytes(
-                                cfgs[idx].upload_codec, setups[idx].params),
-                            model_bytes=setups[idx].model_bytes,
-                            emask=_emask(spec.base.rounds,
-                                         cfgs[idx].eval_every),
-                            cell=idx)
+                            compile_time_s=report.compile_time_s)
+                        evals_total += res.shapley_evals
+                        results.append(res)
+                        if telemetry is not None:
+                            emit_scan_rounds(
+                                telemetry, out_j,
+                                uses_shapley=part.key.needs_sv,
+                                codec_bytes=codec_nbytes(
+                                    cfgs[idx].upload_codec,
+                                    setups[idx].params),
+                                model_bytes=setups[idx].model_bytes,
+                                emask=eval_mask(spec.base.rounds,
+                                                cfgs[idx].eval_every),
+                                cell=idx)
                 per_partition.append(results)
                 reports.append(PartitionReport(
                     label=part.key.label, cell_indices=part.cell_indices,

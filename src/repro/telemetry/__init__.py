@@ -24,7 +24,7 @@ from repro.telemetry.profile import (
     cached_cost_card, cost_card, stage_wall_from_trace, trace_capture,
 )
 from repro.telemetry.trace import (
-    CompileTimer, live_sink, named_stage, record_spans, stage,
+    CompileTimer, live_sink, named_stage, stage,
 )
 
 __all__ = [
@@ -34,5 +34,5 @@ __all__ = [
     "emit_scan_rounds", "run_end_payload", "segment_counters",
     "cached_cost_card", "cost_card", "stage_wall_from_trace",
     "trace_capture",
-    "CompileTimer", "live_sink", "named_stage", "record_spans", "stage",
+    "CompileTimer", "live_sink", "named_stage", "stage",
 ]

@@ -110,9 +110,10 @@ class Telemetry:
       must not change any output (pinned by tests/test_telemetry.py).
     * `trace_dir` opts the engines into a programmatic
       `jax.profiler.start_trace`/`stop_trace` capture window around the
-      run's dispatches (profile.trace_capture): artifacts land in a
-      run_id-stamped subdirectory and a `profile` event reports the
-      per-stage wall recovered from the §15 span annotations.
+      run's set-up and dispatches (profile.trace_capture): artifacts
+      land in a run_id-stamped subdirectory and a `profile` event
+      reports the per-stage wall recovered from the §15 span
+      annotations.
     * `heartbeat_every_s` throttles progress lines (0 = every call);
       lines go to `stream` (default stderr), never into the event file.
     """

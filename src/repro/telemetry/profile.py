@@ -21,16 +21,15 @@ The PR-6 event stream records *when* things happened; this module records
 
   * `trace_capture(telemetry, label)` — the opt-in programmatic
     `jax.profiler.start_trace`/`stop_trace` window (`Telemetry(trace_dir=
-    ...)`): artifacts land in `<trace_dir>/<run_id>/`, and on exit a
+    ...)`), opened before `setup_run` so it holds the host layers'
+    spans too: artifacts land in `<trace_dir>/<run_id>/`, and on exit a
     `profile` event reports per-stage wall seconds recovered from the
-    §15 `TraceAnnotation` spans — parsed out of the profiler's Chrome-
-    trace export when the backend wrote one (`source="trace"`), else
-    from the host-side `SpanRecorder` fallback (`source="host"`).  The
+    §15 `TraceAnnotation` spans in the capture's own trace.  The
     in-scan `named_scope` stages additionally name the HLO regions for
-    device timelines (TPU); the capture window is how those profiles
-    get collected.  Nested/concurrent captures degrade gracefully: if
-    the profiler is already tracing, the window falls back to host-span
-    attribution instead of raising.
+    device timelines (TPU); the capture window is how those profiles get
+    collected.  Nested/concurrent captures degrade gracefully: if the
+    profiler is already tracing, the event says `captured=False` with
+    no walls instead of raising.
 
 Everything here is observation-only: no extra device dispatches, and a
 telemetry-off run never reaches this module.
@@ -39,15 +38,13 @@ from __future__ import annotations
 
 import contextlib
 import glob
-import gzip
-import json
 import os
 from typing import Any, Iterator, Optional
 
 import jax
 
 from repro.launch.compat import aot_compile, cost_analysis_of, memory_stats_of
-from repro.telemetry.trace import SPAN_PREFIX, record_spans
+from repro.telemetry.trace import SPAN_PREFIX
 
 from repro.launch.roofline import peaks_for
 
@@ -124,31 +121,36 @@ def cached_cost_card(jitted, *args, **kwargs) -> Optional[dict]:
 # ---- the capture window --------------------------------------------------
 
 def stage_wall_from_trace(trace_dir: str) -> Optional[dict]:
-    """Per-stage wall seconds from a profiler capture's Chrome trace.
+    """Per-stage wall seconds from a profiler capture's own trace.
 
-    `jax.profiler.stop_trace` exports `plugins/profile/<ts>/*.trace.json
-    .gz`; the §15 `TraceAnnotation` spans appear there as complete events
-    named `repro.<stage>` with microsecond durations.  Returns
+    `jax.profiler.stop_trace` writes `plugins/profile/<ts>/*.xplane.pb`;
+    the §15 `TraceAnnotation` spans appear on its host planes as events
+    named `repro.<stage>` with nanosecond durations.  Returns
     {stage: seconds} summed over all matching spans (newest capture under
-    `trace_dir` wins), or None when no parseable trace exists — the
-    caller then falls back to host-side span timing.  `named_scope`
-    stages annotate device-op timelines instead and stay in the artifact
-    for offline viewers.
+    `trace_dir` wins), or None when no readable trace exists.  The
+    Chrome-trace export beside it is not read: it keeps only the first
+    million events, which the profiler's Python tracer can fill before a
+    run's last span.  `named_scope` stages annotate device-op timelines
+    instead and stay in the artifact for offline viewers.
     """
+    from jax.profiler import ProfileData
+
     paths = sorted(glob.glob(os.path.join(
-        trace_dir, "plugins", "profile", "*", "*.trace.json.gz")))
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
     if not paths:
         return None
     try:
-        with gzip.open(paths[-1], "rt") as f:
-            trace = json.load(f)
+        planes = ProfileData.from_file(paths[-1]).planes
         walls: dict[str, float] = {}
-        for ev in trace.get("traceEvents", []):
-            name = ev.get("name", "")
-            if ev.get("ph") == "X" and name.startswith(SPAN_PREFIX):
-                stage = name[len(SPAN_PREFIX):]
-                walls[stage] = walls.get(stage, 0.0) + \
-                    float(ev.get("dur", 0.0)) / 1e6
+        for plane in planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith(SPAN_PREFIX):
+                        stage = ev.name[len(SPAN_PREFIX):]
+                        walls[stage] = (walls.get(stage, 0.0)
+                                        + ev.duration_ns / 1e9)
         return walls or None
     except Exception:
         return None
@@ -156,15 +158,16 @@ def stage_wall_from_trace(trace_dir: str) -> Optional[dict]:
 
 @contextlib.contextmanager
 def trace_capture(telemetry, label: str = "run") -> Iterator[Any]:
-    """Profiler capture window around a run's dispatches (opt-in).
+    """Profiler capture window around a run's set-up and dispatches
+    (opt-in).
 
     No-op (yields None) unless `telemetry` carries a `trace_dir`.  Active
     windows start `jax.profiler.start_trace` into the run_id-stamped
-    directory, record host `stage()` spans, and on exit stop the trace
-    and emit one `profile` event: where the artifacts are, per-stage wall
-    seconds, and which recovery source produced them.  The caller must
-    block on its dispatches inside the window (the engines do) so spans
-    cover execution, not enqueue.
+    directory, yield that directory, and on exit stop the trace and emit
+    one `profile` event: where the artifacts are, whether the profiler
+    captured, and per-stage wall seconds read from the capture (empty
+    when it did not).  The caller must block on its dispatches inside
+    the window (the engines do) so spans cover execution, not enqueue.
     """
     if telemetry is None or not getattr(telemetry, "trace_dir", None):
         yield None
@@ -175,10 +178,9 @@ def trace_capture(telemetry, label: str = "run") -> Iterator[Any]:
         jax.profiler.start_trace(tdir)
         started = True
     except Exception:
-        pass   # profiler already tracing / unavailable: host spans only
+        pass   # profiler already tracing / unavailable: nothing captured
     try:
-        with record_spans() as rec:
-            yield rec
+        yield tdir
     finally:
         if started:
             try:
@@ -186,7 +188,5 @@ def trace_capture(telemetry, label: str = "run") -> Iterator[Any]:
             except Exception:
                 started = False
         walls = stage_wall_from_trace(tdir) if started else None
-        source = "trace" if walls else "host"
         telemetry.emit("profile", trace_dir=tdir, label=label,
-                       captured=started, source=source,
-                       stage_wall_s=walls or rec.totals())
+                       captured=started, stage_wall_s=walls or {})

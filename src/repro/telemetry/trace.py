@@ -3,11 +3,15 @@
 Three tools, all bit-neutral by construction (DESIGN.md §15):
 
   * `stage(name)` / `named_stage(name)` — stage annotation.  `stage` is a
-    host-side `jax.profiler.TraceAnnotation` context (shows up as a named
-    span on the profiler timeline around a dispatch); `named_stage` is the
-    in-trace `jax.named_scope` (names the HLO ops of a region, so profiles
-    of the fused scan attribute time to select/train/shapley/aggregate/
-    eval instead of one opaque dispatch).  Both are pure metadata.
+    host-side `jax.profiler.TraceAnnotation` (a named span on the
+    profiler timeline, on the device trace's clock) at each host layer
+    boundary of a run: `setup` (setup_run), `operands` (building and
+    placing a dispatch's operands), `scan` / `segment` (the dispatch),
+    `results` (reading the outputs back).  `named_stage` is the in-trace
+    `jax.named_scope` (names the HLO ops of a region, so profiles of the
+    fused scan attribute time to select/train/shapley/aggregate/eval
+    instead of one opaque dispatch; `gather` and `codec` are sub-stages
+    inside `train`).  Both are pure metadata.
 
   * `CompileTimer` — attributes jit compilation via `jax.monitoring`
     duration events (`/jax/core/compile/...`: trace, MLIR lowering,
@@ -33,8 +37,7 @@ from __future__ import annotations
 
 import contextlib
 import threading
-import time
-from typing import Iterator, Optional
+from typing import Iterator
 
 import jax
 
@@ -46,53 +49,10 @@ STAGES = ("select", "train", "shapley", "aggregate", "eval")
 SPAN_PREFIX = "repro."
 
 
-class SpanRecorder:
-    """Host-side record of `stage()` spans: name -> total wall seconds.
-
-    Installed by `record_spans()` (profile.trace_capture uses it as the
-    always-available fallback when the profiler's trace files cannot be
-    parsed) — `stage()` adds its wall duration here whenever a recorder
-    is active."""
-
-    def __init__(self) -> None:
-        self.spans: list[tuple[str, float]] = []
-
-    def add(self, name: str, seconds: float) -> None:
-        self.spans.append((name, seconds))
-
-    def totals(self) -> dict[str, float]:
-        out: dict[str, float] = {}
-        for name, secs in self.spans:
-            out[name] = out.get(name, 0.0) + secs
-        return out
-
-
-_span_recorder: Optional[SpanRecorder] = None
-
-
-@contextlib.contextmanager
-def record_spans() -> Iterator[SpanRecorder]:
-    """Install a SpanRecorder for the enclosed region (re-entrant: an
-    inner recorder shadows the outer one for its extent)."""
-    global _span_recorder
-    prev = _span_recorder
-    rec = SpanRecorder()
-    _span_recorder = rec
-    try:
-        yield rec
-    finally:
-        _span_recorder = prev
-
-
-@contextlib.contextmanager
-def stage(name: str) -> Iterator[None]:
-    """Host-side profiler span around a region of dispatches."""
-    rec = _span_recorder
-    t0 = time.perf_counter() if rec is not None else 0.0
-    with jax.profiler.TraceAnnotation(f"{SPAN_PREFIX}{name}"):
-        yield
-    if rec is not None:
-        rec.add(name, time.perf_counter() - t0)
+def stage(name: str) -> jax.profiler.TraceAnnotation:
+    """Host-side profiler span `repro.<name>` around a host layer or a
+    dispatch: one `TraceAnnotation`, on the device trace's clock."""
+    return jax.profiler.TraceAnnotation(f"{SPAN_PREFIX}{name}")
 
 
 def named_stage(name: str):

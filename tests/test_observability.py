@@ -108,8 +108,11 @@ def test_grid_cost_cards_and_heartbeat_peak(tmp_path):
 
     [prof] = [e for e in tel.events if e["event"] == "profile"]
     assert prof["label"] == "grid"
+    assert prof["captured"] is True
     assert prof["stage_wall_s"].get("segment", 0) > 0
-    assert prof["source"] in ("trace", "host")
+    # the window opens before the cells' set-ups, so it holds them too
+    assert prof["stage_wall_s"].get("setup", 0) > 0
+    assert "source" not in prof
 
     beats = hb.getvalue()
     assert "eta" in beats and "peak" in beats and "MB/dev" in beats
@@ -132,8 +135,37 @@ def test_trace_capture_unit(tmp_path):
         with stage("unit_op"):
             jax.block_until_ready(f(x))
     [prof] = [e for e in tel.events if e["event"] == "profile"]
-    assert prof["captured"] in (True, False)
+    assert prof["captured"] is True
     assert prof["stage_wall_s"]["unit_op"] > 0
+    validate_events(tel.events)
+
+
+def test_scan_capture_window_holds_setup(tmp_path):
+    """A solo run's capture window opens before `setup_run`: its walls
+    hold every host layer's span, each read from the capture's trace."""
+    cfg = FLConfig(engine="scan", selector="greedyfed", **TINY)
+    tel = Telemetry(trace_dir=str(tmp_path / "tr"))
+    run_federated(cfg, telemetry=tel)
+    validate_events(tel.events)
+    [prof] = [e for e in tel.events if e["event"] == "profile"]
+    assert prof["label"] == "run_scan" and prof["captured"] is True
+    walls = prof["stage_wall_s"]
+    for name in ("setup", "operands", "scan", "results"):
+        assert walls.get(name, 0) > 0, name
+
+
+def test_trace_capture_without_profiler_reports_no_walls(tmp_path):
+    """With the profiler already tracing the window cannot start: the
+    event says so and carries no walls (nothing else times the spans)."""
+    tel = Telemetry(trace_dir=str(tmp_path / "inner"))
+    with jax.profiler.trace(str(tmp_path / "outer")):
+        with trace_capture(tel, label="nested"):
+            with stage("unit_op"):
+                jax.block_until_ready(jnp.ones((8,)) * 2)
+    [prof] = [e for e in tel.events if e["event"] == "profile"]
+    assert prof["captured"] is False
+    assert prof["stage_wall_s"] == {}
+    assert "source" not in prof
     validate_events(tel.events)
 
 
