@@ -2,17 +2,11 @@
 
 The selection engines produce a global (M,) id vector each round; this
 package turns it into the cohort's rows without materialising anything
-O(N) beyond the (sharded) client stacks themselves:
-
-  * `kernel.py`  — Pallas TPU gather with scalar-prefetched cohort ids
-                   (the ids live in SMEM and drive the input BlockSpec's
-                   index_map, so each output row's DMA fetches exactly
-                   one table row);
-  * `ref.py`     — the jnp oracle (`jnp.take`), the bitwise contract;
-  * `ops.py`     — the public wrapper: pytree-aware single-device path
-                   (kernel on TPU, ref elsewhere) plus the cross-shard
-                   masked-gather + psum path for client-axis-sharded
-                   stacks (DESIGN.md §16).
+O(N) beyond the (sharded) client stacks themselves.  `ops.py` holds the
+dense path, `jnp.take` on the stack's stored (N, cap, F) shape (XLA
+gathers the M rows; nothing touches the whole table), and the
+cross-shard masked-gather + psum path for client-axis-sharded stacks
+(DESIGN.md §16).  Both copy bits, so both are bitwise `jnp.take`.
 """
 from repro.kernels.cohort_gather.ops import cohort_gather, cohort_take
 

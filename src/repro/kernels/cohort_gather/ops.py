@@ -2,12 +2,13 @@
 
 Two regimes behind one call:
 
-  * dense (`axis_name=None`) — the whole (N, ...) stack is local.  On
-    TPU (`use_kernel=None`) leaves of at least one BLOCK_D tile run the
-    natively compiled Pallas kernel; off-TPU the interpreter would add
-    pure overhead to a copy, and `jnp.take` IS the bitwise reference, so
-    the ref is used.  Small leaves always take the ref, mirroring
-    `prefix_avg`.
+  * dense (`axis_name=None`) — the whole (N, ...) stack is local and the
+    cohort is `jnp.take(arr, ids, axis=0)` on the stack's stored shape.
+    XLA lowers it to one gather that reads and writes only the M
+    selected rows: no reshape of the stack to (N, D), no pad to a tile,
+    and under a replica `vmap` the replica axis folds into the gather's
+    indices instead of a per-replica loop over the whole table
+    (tests/test_tpu_compile.py checks the compiled v5e loop body).
 
   * client-sharded (`axis_name="clients"`) — `arr` is this shard's
     (N/devices, ...) block inside a `shard_map` body and `ids` is the
@@ -19,8 +20,9 @@ Two regimes behind one call:
     psum, bitcast back), sidestepping float-add edge cases (-0.0, NaN
     payloads) that could break the sharded==dense bitwise contract.
 
-Both regimes return bit-identical results to `jnp.take(arr, ids, 0)` on
-the equivalent dense stack; the engines rely on that (DESIGN.md §16).
+A gather copies bits — no arithmetic, no accumulation order — so both
+regimes return bit-identical results to `jnp.take(arr, ids, 0)` on the
+equivalent dense stack; the engines rely on that (DESIGN.md §16).
 """
 from __future__ import annotations
 
@@ -29,10 +31,6 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-
-from repro.kernels import default_interpret, pad_to
-from repro.kernels.cohort_gather.kernel import BLOCK_D, cohort_gather_kernel
-from repro.kernels.cohort_gather.ref import cohort_gather_ref
 
 PyTree = Any
 
@@ -60,45 +58,20 @@ def _cross_shard_take(arr: jax.Array, ids: jax.Array,
 
 
 def cohort_take(arr: jax.Array, ids: jax.Array, *,
-                axis_name: Optional[str] = None,
-                use_kernel: Optional[bool] = None,
-                interpret: Optional[bool] = None,
-                block_d: int = BLOCK_D) -> jax.Array:
+                axis_name: Optional[str] = None) -> jax.Array:
     """Gather rows `ids` (M,) from `arr` (N, ...) -> (M, ...).
 
     With `axis_name` set, `arr` is the local (N/devices, ...) shard of a
     client-axis-sharded stack (see `_cross_shard_take`); otherwise the
-    dense single-device gather.  `use_kernel=None` resolves to
-    TPU-only (a copy gains nothing from the Pallas interpreter);
-    `interpret=None` derives from the backend like the other kernels, so
-    on TPU the kernel always runs compiled.
+    dense gather on the stored shape.
     """
     if axis_name is not None:
         return _cross_shard_take(arr, ids, axis_name)
-    if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
-    if interpret is None:
-        interpret = default_interpret()
-    m = ids.shape[0]
-    flat = arr.reshape(arr.shape[0], -1)
-    d = flat.shape[1]
-    if not use_kernel or d < block_d:
-        out = cohort_gather_ref(flat, ids)
-    else:
-        padded = pad_to(flat, block_d)
-        out = cohort_gather_kernel(padded, ids, block_d=block_d,
-                                   interpret=interpret)
-        out = out[:, :d]
-    return out.reshape((m,) + arr.shape[1:])
+    return jnp.take(arr, ids, axis=0)
 
 
 def cohort_gather(tree: PyTree, ids: jax.Array, *,
-                  axis_name: Optional[str] = None,
-                  use_kernel: Optional[bool] = None,
-                  interpret: Optional[bool] = None,
-                  block_d: int = BLOCK_D) -> PyTree:
+                  axis_name: Optional[str] = None) -> PyTree:
     """Pytree version: every (N, ...) leaf gathered to (M, ...)."""
-    take = partial(cohort_take, ids=ids, axis_name=axis_name,
-                   use_kernel=use_kernel, interpret=interpret,
-                   block_d=block_d)
-    return jax.tree.map(lambda leaf: take(leaf), tree)
+    return jax.tree.map(partial(cohort_take, ids=ids, axis_name=axis_name),
+                        tree)

@@ -1,4 +1,5 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode."""
+"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode;
+the cohort gather vs numpy indexing."""
 import functools
 
 import jax
@@ -9,9 +10,7 @@ import pytest
 from repro.kernels.ce_loss.kernel import ce_loss_kernel
 from repro.kernels.ce_loss.ops import ce_loss
 from repro.kernels.ce_loss.ref import ce_loss_ref
-from repro.kernels.cohort_gather.kernel import cohort_gather_kernel
 from repro.kernels.cohort_gather.ops import cohort_gather, cohort_take
-from repro.kernels.cohort_gather.ref import cohort_gather_ref
 from repro.kernels.delta_codec.kernel import LANES, delta_codec_kernel
 from repro.kernels.delta_codec.ops import delta_codec_roundtrip
 from repro.kernels.delta_codec.ref import delta_codec_ref
@@ -149,47 +148,57 @@ def test_ce_loss_wrapper_handles_unaligned_vocab(key):
 
 
 # ------------------------------------------------------ cohort_gather ------
-# A gather copies bits, so every comparison below is exact equality —
-# including bf16 and repeated/boundary ids.
-@pytest.mark.parametrize("n,d,m", [(7, 2048, 3), (16, 4096, 5),
-                                   (100, 2048, 20), (33, 6144, 8),
-                                   (300, 4096, 3)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_cohort_gather_kernel_matches_ref(n, d, m, dtype, key):
-    table = jax.random.normal(key, (n, d), dtype)
-    ids = jax.random.randint(key, (m,), 0, n)
-    got = cohort_gather_kernel(table, ids, block_d=2048, interpret=True)
-    want = cohort_gather_ref(table, ids)
+# A gather copies bits, so every comparison below is exact equality
+# against numpy indexing of the stack as stored (N, cap, F) — including
+# bf16, int tables and repeated/boundary ids.
+def _assert_rows(got, table, ids):
     assert got.dtype == table.dtype
-    np.testing.assert_array_equal(np.asarray(got, np.float32),
-                                  np.asarray(want, np.float32))
+    assert got.shape == ids.shape + table.shape[1:]
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(table)[np.asarray(ids)])
 
 
-def test_cohort_gather_kernel_repeated_and_boundary_ids(key):
-    n, d = 9, 2048
-    table = jax.random.normal(key, (n, d))
+@pytest.mark.parametrize("n,m,cap,f", [(7, 3, 16, 128), (16, 5, 32, 128),
+                                       (100, 20, 8, 256), (33, 8, 48, 128),
+                                       (300, 3, 53, 784)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_cohort_take_matches_numpy(n, m, cap, f, dtype, key):
+    """Up to the paper's MNIST client stacks: N = 300, 53 rows of 784."""
+    table = jax.random.normal(key, (n, cap, f), dtype)
+    ids = jax.random.randint(key, (m,), 0, n)
+    _assert_rows(jax.jit(cohort_take)(table, ids), table, ids)
+
+
+def test_cohort_take_repeated_and_boundary_ids(key):
+    n = 9
+    table = jax.random.normal(key, (n, 12, 40))
     ids = jnp.array([0, n - 1, 3, 3, 0], jnp.int32)
-    got = cohort_gather_kernel(table, ids, block_d=2048, interpret=True)
-    np.testing.assert_array_equal(np.asarray(got),
-                                  np.asarray(table)[np.asarray(ids)])
+    _assert_rows(cohort_take(table, ids), table, ids)
 
 
-def test_cohort_take_pads_unaligned_feature_dim(key):
-    """Non-divisible flattened D: padded to the kernel tile, sliced back,
-    still bit-exact against jnp.take."""
-    table = jax.random.normal(key, (11, 37, 95))    # 37*95 = 3515
+def test_cohort_take_unaligned_trailing_dims(key):
+    """Trailing dims off the (8, 128) tile are gathered as stored."""
+    table = jax.random.normal(key, (11, 37, 95))
     ids = jnp.array([10, 0, 4], jnp.int32)
-    got = cohort_take(table, ids, use_kernel=True, interpret=True)
-    np.testing.assert_array_equal(np.asarray(got),
-                                  np.asarray(table)[np.asarray(ids)])
+    _assert_rows(cohort_take(table, ids), table, ids)
 
 
 def test_cohort_take_integer_table(key):
-    table = jax.random.randint(key, (13, 2048), -1000, 1000, jnp.int32)
+    table = jax.random.randint(key, (13, 5, 784), -1000, 1000, jnp.int32)
     ids = jnp.array([12, 12, 1, 0], jnp.int32)
-    got = cohort_take(table, ids, use_kernel=True, interpret=True)
-    np.testing.assert_array_equal(np.asarray(got),
-                                  np.asarray(table)[np.asarray(ids)])
+    _assert_rows(cohort_take(table, ids), table, ids)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32])
+def test_cohort_take_vmap_per_replica_ids(dtype, key):
+    """The grid's replica vmap: (R, N, cap, F) stacks, (R, M) ids."""
+    r, n, m = 8, 30, 3
+    table = (jax.random.normal(key, (r, n, 7, 130)) * 100).astype(dtype)
+    ids = jax.random.randint(key, (r, m), 0, n)
+    got = jax.jit(jax.vmap(cohort_take))(table, ids)
+    assert got.shape == (r, m, 7, 130)
+    for i in range(r):
+        _assert_rows(got[i], table[i], ids[i])
 
 
 def test_cohort_gather_tree_wrapper_ragged_leaves(key):
@@ -199,7 +208,7 @@ def test_cohort_gather_tree_wrapper_ragged_leaves(key):
             "b": jax.random.normal(key, (10, 5000)),
             "nv": jax.random.randint(key, (10,), 0, 64, jnp.int32)}
     ids = jnp.array([9, 2, 2, 0, 7], jnp.int32)
-    got = cohort_gather(tree, ids, use_kernel=True, interpret=True)
+    got = cohort_gather(tree, ids)
     for name, leaf in tree.items():
         assert got[name].shape == (5,) + leaf.shape[1:]
         np.testing.assert_array_equal(np.asarray(got[name]),
